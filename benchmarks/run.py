@@ -877,7 +877,7 @@ BENCHES = [table2_diversification_time, fig4_cpu_search, fig5_degree_sweep,
            hotpath_micro, search_backend_compare, roofline_table]
 
 
-def _persist_rows(tier: str) -> str:
+def _persist_rows(tier: str, dev: dict) -> str:
     """Append this run's rows to ``BENCH_<tier>.json`` at the repo root —
     a timestamped history so regressions are diffable across commits
     (bounded to the last 50 runs per tier).  Returns the file path."""
@@ -893,6 +893,7 @@ def _persist_rows(tier: str) -> str:
     history.setdefault("runs", []).append({
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         "quick": QUICK,
+        "device": dev,
         "rows": [{"name": n, "us_per_call": us, "derived": d}
                  for n, us, d in ROWS],
     })
@@ -902,22 +903,41 @@ def _persist_rows(tier: str) -> str:
     return path
 
 
-def main() -> None:
+def device() -> dict:
+    """The device the rows were measured on, as JAX reports it."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def main() -> int:
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     # REPRO_BENCH_ONLY=serve runs just the benches whose name contains the
     # substring (the CI serving smoke uses this)
     only = os.environ.get("REPRO_BENCH_ONLY", "")
+    dev = device()
+    print(f"# device platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
     print("name,us_per_call,derived")
+    failed = []
     for bench in BENCHES:
         if only and only not in bench.__name__:
             continue
         try:
             bench()
         except Exception as e:  # noqa: BLE001
+            failed.append(bench.__name__)
             emit(f"{bench.__name__}/ERROR", -1.0, repr(e)[:120])
     print(f"# {len(ROWS)} rows", flush=True)
-    path = _persist_rows(only or "all")
+    path = _persist_rows(only or "all", dev)
     print(f"# rows persisted to {path}", flush=True)
+    if failed:
+        print(f"# FAILED benches: {', '.join(failed)}", flush=True)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -24,6 +24,8 @@ from __future__ import annotations
 
 import dataclasses
 import difflib
+import logging
+import time
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +34,8 @@ from repro.core import metrics as M
 from repro.core.diversify import (PackedGraph, add_bridges, append_reverse,
                                   relaxed_gd, soft_gd)
 from repro.core.knn_build import nn_descent
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -200,8 +204,18 @@ def build_graph(X, cfg, *, stages=None, tile: int = 2048,
         backend=getattr(cfg, "kernel_backend", "auto"),
         gather_fused=getattr(cfg, "gather_fused", None),
         knn_ids=knn_ids, knn_dists=knn_dists)
+    # stage times only when someone reads them (waiting per stage stops the
+    # next stage's trace and compile from overlapping this one's run), and
+    # never inside a traced (mesh shard) build
+    timed = (log.isEnabledFor(logging.INFO)
+             and not isinstance(state.X, jax.core.Tracer))
     for name, fn in fns:
+        t0 = time.perf_counter()
         fn(state)
+        if timed:
+            jax.block_until_ready((state.knn_ids, state.neighbors))
+            log.info("build stage %s: %.3f s", name,
+                     time.perf_counter() - t0)
     if state.neighbors is None:
         raise ValueError(
             f"build pipeline {names} produced no graph — it must include a "

@@ -38,7 +38,6 @@ from repro.configs.base import ANNConfig
 from repro.core.diversify import PackedGraph
 from repro.core.search_large import _large_batch_search
 from repro.core.search_small import _small_batch_search
-from repro.utils.compat import shard_map
 
 PAD_ID = jnp.int32(-1)
 INF = jnp.float32(3.4e38)
@@ -98,7 +97,7 @@ def make_build_fn(mesh: Mesh, cfg: ANNConfig):
         return g.neighbors, g.lambdas, g.degrees, \
             (g.hubs if g.hubs is not None else jnp.zeros((0,), jnp.int32))
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_build, mesh=mesh,
         in_specs=(P(d_ax, None),),
         out_specs=(P(d_ax, None), P(d_ax, None), P(d_ax), P(d_ax)),
@@ -356,7 +355,7 @@ def make_search_fn(mesh: Mesh, cfg: ANNConfig, *, kind: str = "large",
         in_specs = in_specs + (P(d_ax), P(None, None), P(None))
         if quantized:  # replicated delta codes + scales
             in_specs = in_specs + (P(None, None), P(None))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=in_specs + (q_spec,),
         out_specs=(out_spec, out_spec),

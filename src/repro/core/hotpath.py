@@ -44,6 +44,16 @@ from repro.kernels import visited as _vf
 
 INF = jnp.float32(3.4e38)
 
+# (primitive, path, interpret) -> trace count, recorded when a Pallas
+# primitive is traced: which kernel path each primitive took at the shapes
+# it saw, and whether it ran in interpret mode (chip_smoke.py reports it)
+PATHS: dict = {}
+
+
+def _note(primitive: str, path: str, interp: bool) -> None:
+    key = (primitive, path, bool(interp))
+    PATHS[key] = PATHS.get(key, 0) + 1
+
 
 def gather_dispatch(mode: str, interp: bool, fits: bool) -> bool:
     """The gather-fused placement decision, named so tests can pin it.
@@ -117,6 +127,8 @@ def _q3_of(Q, X, q_idx):
 
 
 def _interp(interpret):
+    """Interpret mode unless on a TPU (or told otherwise): the CPU tests
+    run the real kernel bodies through the Pallas interpreter."""
     return (jax.default_backend() != "tpu") if interpret is None else interpret
 
 
@@ -188,6 +200,8 @@ class _PallasBackend:
         fits = _l2.gather_fused_fits(Kq, C, d, self_q=self_q,
                                      itemsize=X.dtype.itemsize)
         use_fused = gather_dispatch(mode, interp, fits)
+        _note("neighbor_distances",
+              "fused_gather" if use_fused else "gather_then_block", interp)
         idx_c = jnp.clip(idx, 0, X.shape[0] - 1)
         sc = None if scales is None else scales[idx_c]
         if not use_fused:
@@ -209,8 +223,10 @@ class _PallasBackend:
 
     @staticmethod
     def rank_merge(dists, ids, *, keep, mask=None, interpret=None):
+        interp = _interp(interpret)
+        _note("rank_merge", "bitonic", interp)
         return _topk.rank_merge_pallas(dists, ids, mask, keep=keep,
-                                       interpret=_interp(interpret))
+                                       interpret=interp)
 
     @staticmethod
     def scan_distances(Q, Xd, *, metric, mask=None, interpret=None,
@@ -221,15 +237,19 @@ class _PallasBackend:
         # gemm's accumulation grouping)
         m = jnp.ones((Xd.shape[0],), bool) if mask is None else mask
         sc = None if scales is None else scales[None]
+        interp = _interp(interpret)
+        _note("scan_distances", "block", interp)
         out = _l2.block_distances_pallas(Q[None], Xd[None], m[None], sc,
                                          metric=metric, bs=1,
-                                         interpret=_interp(interpret))
+                                         interpret=interp)
         return out[0]
 
     @staticmethod
     def visited_filter(table, ids, valid, *, interpret=None):
+        interp = _interp(interpret)
+        _note("visited_filter", "hash_table", interp)
         return _vf.visited_filter_pallas(table, ids, valid,
-                                         interpret=_interp(interpret))
+                                         interpret=interp)
 
 
 _REGISTRY = {"xla": _XlaBackend, "pallas": _PallasBackend}

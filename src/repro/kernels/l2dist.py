@@ -99,8 +99,10 @@ def _score_block(q, v, m, *, metric: str, pin: bool = False):
             qn = jnp.sum(q * q, axis=2)
             vn = jnp.sum(v * v, axis=2)
         dist = qn[:, :, None] + vn[:, None, :] - 2.0 * dots
-    return jnp.where((m != 0)[:, None, :], dist,
-                     jnp.asarray(3.4e38, dist.dtype))
+    # widen before the broadcast: Mosaic cannot insert a unit dim into a
+    # bool (i1) vector, but can into an int32 one
+    keep = m.astype(jnp.int32)[:, None, :] != 0
+    return jnp.where(keep, dist, jnp.asarray(3.4e38, dist.dtype))
 
 
 def _block_kernel(q_ref, v_ref, m_ref, o_ref, *, metric: str):
@@ -250,11 +252,19 @@ def _gather_tile_bytes(Kq: int, C: int, d: int, *, self_q: bool,
                        itemsize: int = 4) -> int:
     """Bytes of one gather-fused block set per row of tile: Q tile (unless
     the query side is gathered from the same ids), the double-buffered
-    neighbor scratch (at the database dtype's actual width — int8 codes
-    DMA 1 byte/element and bill their fp32 scale row), mask, and output."""
+    neighbor scratch (at the row's DMA width — int8 codes move as
+    :func:`pack_words` rows, d bytes rounded up to whole 512-byte lane
+    rows, and bill their fp32 scale row), mask, and output."""
     q = 0 if self_q else Kq * d * 4
     scales = 0 if itemsize == 4 else C * 4
-    return q + 2 * C * d * itemsize + scales + C + Kq * C * 4
+    row = d * 4 if itemsize == 4 else packed_row_bytes(d)
+    return q + 2 * C * row + scales + C + Kq * C * 4
+
+
+def packed_row_bytes(d: int) -> int:
+    """Bytes of one :func:`pack_words` row: d int8 codes as int32 words,
+    padded to whole 128-lane rows."""
+    return -(-d // 512) * 512
 
 
 def gather_fused_fits(Kq: int, C: int, d: int, *, self_q: bool = False,
@@ -277,52 +287,82 @@ def _pick_bs_fused(S: int, Kq: int, C: int, d: int, *,
     return bs
 
 
-def _gather_body(idx_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf, sem, *,
-                 metric: str, bs: int, C: int, pin: bool = False):
-    """One grid step = one row tile.  idx_ref [Sp, C] is scalar-prefetched
-    (SMEM), so the DMA targets are known before the body runs; x_hbm is the
-    whole database in HBM/ANY; vbuf [2, bs, C, d] revolves across the grid.
-    ``s_ref`` (quantized path only) carries the gathered per-row fp32
-    scales; the int8 tile dequantizes in-register after the DMA.  ``pin``
-    — see :func:`_block_kernel_quant` (interpret-mode fma-fusion guard).
+def pack_words(codes):
+    """int8 codes [N, d] -> int32 words [N, Wd] for the fused gather, Wd =
+    d/4 rounded up to whole 128-lane rows (zero words pad the tail).
+
+    Mosaic tiles an int8 HBM array by (8, 128) with four rows interleaved
+    per 32-bit word, so one int8 row is not contiguous and cannot be a DMA
+    source; an int32 row of whole 128-lane tiles is.  Byte k of word j
+    holds element ``k*d/4 + j`` (chunk-major), so :func:`_unpack_words`
+    restores the natural order with a lane concat of four contiguous
+    chunks — no interleave."""
+    N, d = codes.shape
+    q = d // 4
+    w = jax.lax.bitcast_convert_type(
+        codes.reshape(N, 4, q).transpose(0, 2, 1), jnp.int32)
+    return jnp.pad(w, ((0, 0), (0, -(-q // 128) * 128 - q)))
+
+
+def _unpack_words(w, d: int):
+    """[..., Wd] int32 words of :func:`pack_words` -> [..., d] fp32 with
+    the exact int8 values (sign-extending byte extraction)."""
+    w = w[..., :d // 4]
+    chunks = [jax.lax.shift_right_arithmetic(
+        jax.lax.shift_left(w, jnp.int32(24 - 8 * k)), jnp.int32(24))
+        for k in range(4)]
+    return jnp.concatenate(chunks, axis=-1).astype(jnp.float32)
+
+
+def _gather_body(cur_ref, nxt_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf,
+                 sem, *, metric: str, bs: int, C: int, d: int,
+                 pin: bool = False):
+    """One grid step = one row tile.  ``cur_ref``/``nxt_ref`` [bs, C] are
+    this tile's and the next tile's neighbor ids, each an SMEM block of
+    its own (SMEM use is fixed by the tile, never by S: a whole [S, C]
+    scalar prefetch outgrows the 1 MiB SMEM at large batches).  x_hbm is
+    the whole database in HBM/ANY; vbuf [2, bs, C, d] revolves across the
+    grid.  ``s_ref`` (quantized path only) carries the gathered per-row
+    fp32 scales; the int8 tile dequantizes in-register after the DMA.
+    ``pin`` — see :func:`_block_kernel_quant` (interpret-mode fma-fusion
+    guard).  On that path x_hbm holds :func:`pack_words` rows.
     """
     i = pl.program_id(0)
     n = pl.num_programs(0)
     G = span_group(C)  # aligned-group width for span-coalesced copies
 
-    def _dma(slot, tile, r):
+    def _dma(slot, ids_ref, r):
         # r enumerates the bs*C neighbor rows of the tile
         s, c = r // C, jax.lax.rem(r, C)
         return pltpu.make_async_copy(
-            x_hbm.at[idx_ref[tile * bs + s, c]],
+            x_hbm.at[ids_ref[s, c]],
             vbuf.at[slot, s, c],
             sem.at[slot])
 
-    def _span(tile, g):
+    def _span(ids_ref, g):
         """Group g of the tile: (row-in-tile, lane offset, base id, ok)
-        where ok means the G prefetched ids form one contiguous ascending
-        run — a single multi-row HBM slice.  Layout-packed graphs
-        (DESIGN.md §10) make this the common case.  All-SMEM scalar
-        reads, recomputed identically at issue and wait time so starts
-        and waits pair up; contiguity also bounds the slice (the last id
-        is pre-clipped < N, so base + G <= N)."""
+        where ok means the G ids form one contiguous ascending run — a
+        single multi-row HBM slice.  Layout-packed graphs (DESIGN.md §10)
+        make this the common case.  All-SMEM scalar reads, recomputed
+        identically at issue and wait time so starts and waits pair up;
+        contiguity also bounds the slice (the last id is pre-clipped < N,
+        so base + G <= N)."""
         gpr = C // G
         s, c0 = g // gpr, jax.lax.rem(g, gpr) * G
-        base = idx_ref[tile * bs + s, c0]
+        base = ids_ref[s, c0]
         ok = base >= 0
         for j in range(1, G):
-            ok = jnp.logical_and(ok, idx_ref[tile * bs + s, c0 + j]
-                                 == base + j)
+            ok = jnp.logical_and(ok, ids_ref[s, c0 + j] == base + j)
         return s, c0, base, ok
 
-    def _span_dma(slot, tile, g):
-        s, c0, base, _ = _span(tile, g)
+    def _span_dma(slot, ids_ref, g):
+        s, c0, base, _ = _span(ids_ref, g)
         return pltpu.make_async_copy(
             x_hbm.at[pl.ds(base, G)],
             vbuf.at[slot, s, pl.ds(c0, G)],
             sem.at[slot])
 
-    def _sweep(slot, tile, act):
+    def _sweep(slot, ids_ref, act):
         """Drive every DMA of a tile through ``act`` (start or wait).
         G == 1: the original per-row enumeration.  Else per group: one
         coalesced copy when the span predicate holds, the G per-row
@@ -330,43 +370,40 @@ def _gather_body(idx_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf, sem, *,
         same predicates, so every started copy gets one matching wait."""
         if G == 1:
             def body(r, carry):
-                act(_dma(slot, tile, r))
+                act(_dma(slot, ids_ref, r))
                 return carry
             jax.lax.fori_loop(0, bs * C, body, 0)
             return
 
         def body(g, carry):
-            s, c0, _, ok = _span(tile, g)
+            s, c0, _, ok = _span(ids_ref, g)
 
             @pl.when(ok)
             def _():
-                act(_span_dma(slot, tile, g))
+                act(_span_dma(slot, ids_ref, g))
 
             @pl.when(jnp.logical_not(ok))
             def _():
                 for j in range(G):
-                    act(_dma(slot, tile, s * C + c0 + j))
+                    act(_dma(slot, ids_ref, s * C + c0 + j))
             return carry
         jax.lax.fori_loop(0, bs * (C // G), body, 0)
 
-    def _issue(slot, tile):
-        _sweep(slot, tile, lambda cp: cp.start())
-
-    def _wait(slot, tile):
-        _sweep(slot, tile, lambda cp: cp.wait())
-
     @pl.when(i == 0)
     def _():
-        _issue(0, 0)
+        _sweep(0, cur_ref, lambda cp: cp.start())
 
     @pl.when(i + 1 < n)  # prefetch the next tile's rows behind this compute
     def _():
-        _issue((i + 1) % 2, i + 1)
+        _sweep((i + 1) % 2, nxt_ref, lambda cp: cp.start())
 
     slot = jax.lax.rem(i, 2)
-    _wait(slot, i)
+    _sweep(slot, cur_ref, lambda cp: cp.wait())
 
-    v = vbuf[slot].astype(jnp.float32)             # [bs, C, d]
+    if s_ref is None:
+        v = vbuf[slot].astype(jnp.float32)         # [bs, C, d]
+    else:
+        v = _unpack_words(vbuf[slot], d)           # int8 codes -> fp32
     if s_ref is not None:
         v = v * s_ref[...][:, :, None]
         if pin:
@@ -375,25 +412,24 @@ def _gather_body(idx_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf, sem, *,
     o_ref[...] = _score_block(q, v, m_ref[...], metric=metric, pin=pin)
 
 
-def _gather_block_kernel(idx_ref, q_ref, m_ref, x_hbm, o_ref, vbuf, sem, *,
-                         metric: str, bs: int, C: int):
-    _gather_body(idx_ref, q_ref, None, m_ref, x_hbm, o_ref, vbuf, sem,
-                 metric=metric, bs=bs, C=C)
+def _gather_block_kernel(cur_ref, nxt_ref, q_ref, m_ref, x_hbm, o_ref, vbuf,
+                         sem, **kw):
+    _gather_body(cur_ref, nxt_ref, q_ref, None, m_ref, x_hbm, o_ref, vbuf,
+                 sem, **kw)
 
 
-def _gather_block_kernel_quant(idx_ref, q_ref, s_ref, m_ref, x_hbm, o_ref,
-                               vbuf, sem, *, metric: str, bs: int, C: int,
-                               pin: bool = False):
-    _gather_body(idx_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf, sem,
-                 metric=metric, bs=bs, C=C, pin=pin)
+def _gather_block_kernel_quant(cur_ref, nxt_ref, q_ref, s_ref, m_ref, x_hbm,
+                               o_ref, vbuf, sem, **kw):
+    _gather_body(cur_ref, nxt_ref, q_ref, s_ref, m_ref, x_hbm, o_ref, vbuf,
+                 sem, **kw)
 
 
-def _self_q_gather_kernel(idx_ref, m_ref, x_hbm, o_ref, vbuf, sem, *,
-                          metric: str, bs: int, C: int):
+def _self_q_gather_kernel(cur_ref, nxt_ref, m_ref, x_hbm, o_ref, vbuf, sem,
+                          **kw):
     """self_q variant: the query rows ARE the gathered neighbor rows (the
     diversify tiles' [T, K, K] pairwise blocks), so no Q input at all."""
-    _gather_body(idx_ref, None, None, m_ref, x_hbm, o_ref, vbuf, sem,
-                 metric=metric, bs=bs, C=C)
+    _gather_body(cur_ref, nxt_ref, None, None, m_ref, x_hbm, o_ref, vbuf,
+                 sem, **kw)
 
 
 @functools.partial(jax.jit,
@@ -413,9 +449,10 @@ def gather_block_distances_pallas(Q, X, idx, mask, scales=None, *,
     [S, C, d] neighbor buffer.
 
     With ``scales`` [S, C] float32 (the per-row scales pre-gathered by the
-    same idx), X is the int8 code matrix: the DMA streams 1-byte rows
-    (~4x less HBM->VMEM traffic) and the tile dequantizes in-register
-    before the contraction.
+    same idx), X is the int8 code matrix: the DMA streams packed int32
+    rows (:func:`pack_words`; d bytes rounded up to 512, so ~3.75x less
+    HBM->VMEM traffic than fp32 at d=960 and none at d=128) and the tile
+    dequantizes in-register before the contraction.
     """
     S, C = idx.shape
     d = X.shape[1]
@@ -423,61 +460,46 @@ def gather_block_distances_pallas(Q, X, idx, mask, scales=None, *,
     if bs is None:
         bs = _pick_bs_fused(S, Kq, C, d, self_q=self_q,
                             itemsize=X.dtype.itemsize)
+    if scales is not None:
+        if d % 4:
+            raise ValueError(f"fused int8 gather needs d % 4 == 0, got {d}")
+        X = pack_words(X)
     Sp = -(-S // bs) * bs
+    n_tiles = Sp // bs
     ip = jnp.pad(idx, ((0, Sp - S), (0, 0)))
-    mp = jnp.pad(mask.astype(jnp.int8), ((0, Sp - S), (0, 0)))
-    scratch = [pltpu.VMEM((2, bs, C, d), X.dtype),
-               pltpu.SemaphoreType.DMA((2,))]
+    mp = jnp.pad(mask.astype(jnp.int32), ((0, Sp - S), (0, 0)))
+    # this tile's ids and the next tile's (the DMA prefetch), SMEM blocks
+    ids_specs = [
+        pl.BlockSpec((bs, C), lambda i: (i, 0), memory_space=pltpu.SMEM),
+        pl.BlockSpec((bs, C), lambda i: (jnp.minimum(i + 1, n_tiles - 1), 0),
+                     memory_space=pltpu.SMEM),
+    ]
+    row_spec = pl.BlockSpec((bs, C), lambda i: (i, 0))
+    q_spec = pl.BlockSpec((bs, Kq, d), lambda i: (i, 0, 0))
+    x_spec = pl.BlockSpec(memory_space=pl.ANY)
     if self_q:
         kernel = functools.partial(_self_q_gather_kernel, metric=metric,
-                                   bs=bs, C=C)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(Sp // bs,),
-            in_specs=[
-                pl.BlockSpec((bs, C), lambda i, idx_ref: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=pl.BlockSpec((bs, Kq, C), lambda i, idx_ref: (i, 0, 0)),
-            scratch_shapes=scratch,
-        )
-        args = (ip, mp, X)
+                                   bs=bs, C=C, d=d)
+        in_specs = ids_specs + [row_spec, x_spec]
+        args = (ip, ip, mp, X)
     elif scales is not None:
         kernel = functools.partial(_gather_block_kernel_quant, metric=metric,
-                                   bs=bs, C=C, pin=interpret)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(Sp // bs,),
-            in_specs=[
-                pl.BlockSpec((bs, Kq, d), lambda i, idx_ref: (i, 0, 0)),
-                pl.BlockSpec((bs, C), lambda i, idx_ref: (i, 0)),
-                pl.BlockSpec((bs, C), lambda i, idx_ref: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=pl.BlockSpec((bs, Kq, C), lambda i, idx_ref: (i, 0, 0)),
-            scratch_shapes=scratch,
-        )
+                                   bs=bs, C=C, d=d, pin=interpret)
+        in_specs = ids_specs + [q_spec, row_spec, row_spec, x_spec]
         Qp = jnp.pad(Q, ((0, Sp - S), (0, 0), (0, 0)))
         sp = jnp.pad(scales.astype(jnp.float32), ((0, Sp - S), (0, 0)))
-        args = (ip, Qp, sp, mp, X)
+        args = (ip, ip, Qp, sp, mp, X)
     else:
         kernel = functools.partial(_gather_block_kernel, metric=metric,
-                                   bs=bs, C=C)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(Sp // bs,),
-            in_specs=[
-                pl.BlockSpec((bs, Kq, d), lambda i, idx_ref: (i, 0, 0)),
-                pl.BlockSpec((bs, C), lambda i, idx_ref: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-            ],
-            out_specs=pl.BlockSpec((bs, Kq, C), lambda i, idx_ref: (i, 0, 0)),
-            scratch_shapes=scratch,
-        )
+                                   bs=bs, C=C, d=d)
+        in_specs = ids_specs + [q_spec, row_spec, x_spec]
         Qp = jnp.pad(Q, ((0, Sp - S), (0, 0), (0, 0)))
-        args = (ip, Qp, mp, X)
+        args = (ip, ip, Qp, mp, X)
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid=(n_tiles,), in_specs=in_specs,
+        out_specs=pl.BlockSpec((bs, Kq, C), lambda i: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, bs, C, X.shape[1]), X.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
         out_shape=jax.ShapeDtypeStruct((Sp, Kq, C), jnp.float32),
         interpret=interpret,
     )(*args)

@@ -8,7 +8,8 @@ bitonic rank-merge) with W probes per candidate lane.
 
 Layout: ``table`` [B, W, S] int32 — S buckets (power of two, last axis so
 the TPU lane dimension does the probing) × W ways per bucket, ``EMPTY``
-= -1 (node ids are always >= 0).  An id hashes to one bucket
+= -1 (node ids are always >= 0).  The probe flattens the way and bucket
+axes into one lane axis of W*S.  An id hashes to one bucket
 (Fibonacci/Knuth multiplicative hash on the HIGH bits via a logical right
 shift); membership is "any way equals id"; insertion takes the first
 empty way.  A full bucket treats the id as already visited — a safe
@@ -50,84 +51,91 @@ def hash_bucket(ids, shift: int):
     return jax.lax.shift_right_logical(ids * jnp.int32(_GOLD), shift)
 
 
-def lane_step(tab, lid, lval, *, shift: int):
+def lane_step(tab, lid, lval, *, n_buckets: int):
     """Probe-and-insert ONE lane across the row batch.
 
-    ``tab`` [B, W, S] int32, ``lid`` [B] int32, ``lval`` [B] bool ->
-    ``(tab', fresh [B] bool)`` where ``fresh`` means: valid, not already
-    present, and inserted (bucket had a free way).  Pure int32
-    compare/select — the single formulation both backends execute, so
-    they agree bitwise by construction.
+    ``tab`` [B, W*S] int32 (the [B, W, S] table with its way and bucket
+    axes flattened: lane ``w*S + s`` is way w of bucket s), ``lid`` [B, 1]
+    int32, ``lval`` [B, 1] bool -> ``(tab', fresh [B, 1] bool)`` where
+    ``fresh`` means: valid, not already present, and inserted (bucket had
+    a free way).  Pure int32 compare/select on 2-D arrays with lane
+    broadcasts — the single formulation both backends execute (and one
+    Mosaic lowers without shape casts), so they agree bitwise by
+    construction.
     """
-    B, W, S = tab.shape
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (B, S), 1)
-    iota_w = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
-    sel = iota_s == hash_bucket(lid, shift)[:, None]            # [B, S]
-    in_bucket = (tab == lid[:, None, None]) & sel[:, None, :]
-    hit = jnp.any(jnp.any(in_bucket, axis=2), axis=1)           # [B]
-    emptyw = jnp.any((tab == jnp.int32(VF_EMPTY)) & sel[:, None, :], axis=2)
-    slot = jnp.min(jnp.where(emptyw, iota_w, W), axis=1)        # first free
-    fresh = lval & (~hit) & (slot < W)
-    wmask = sel[:, None, :] & (iota_w == slot[:, None])[:, :, None] \
-        & fresh[:, None, None]
-    return jnp.where(wmask, lid[:, None, None], tab), fresh
+    B, L = tab.shape
+    S = n_buckets
+    W = L // S
+    lane = jax.lax.broadcasted_iota(jnp.int32, (B, L), 1)
+    way = lane // S
+    sel = (lane & (S - 1)) == hash_bucket(lid, shift_for(S))    # [B, L]
+    hit = jnp.max(jnp.where(sel & (tab == lid), 1, 0), axis=1,
+                  keepdims=True) > 0                             # [B, 1]
+    emptyw = sel & (tab == jnp.int32(VF_EMPTY))
+    slot = jnp.min(jnp.where(emptyw, way, W), axis=1, keepdims=True)
+    fresh = lval & jnp.logical_not(hit) & (slot < W)
+    wmask = sel & (way == slot) & fresh
+    return jnp.where(wmask, lid, tab), fresh
 
 
 def visited_filter_xla(table, ids, valid):
     """Reference path: lanes applied sequentially with ``lax.scan``."""
-    shift = shift_for(table.shape[2])
+    B, W, S = table.shape
+    shift_for(S)  # validates the bucket count
 
     def lane(tab, xs):
         lid, lval = xs
-        return lane_step(tab, lid, lval, shift=shift)
+        tab, fresh = lane_step(tab, lid[:, None], lval[:, None],
+                               n_buckets=S)
+        return tab, fresh[:, 0]
 
-    table2, fresh_t = jax.lax.scan(lane, table, (ids.T, valid.T))
-    return table2, fresh_t.T
+    table2, fresh_t = jax.lax.scan(lane, table.reshape(B, W * S),
+                                   (ids.T, valid.T))
+    return table2.reshape(B, W, S), fresh_t.T
 
 
-def _vf_kernel(ids_ref, val_ref, tab_ref, tab_out, fresh_ref, *, shift):
+def _vf_kernel(ids_ref, val_ref, tab_ref, tab_out, fresh_ref, *,
+               n_buckets):
     """One row-block: table resident in VMEM, lanes statically unrolled
-    (M is a trace constant; per-lane work is a handful of [bs, W, S]
+    (M is a trace constant; per-lane work is a handful of [bs, W*S]
     compare/selects)."""
     tab = tab_ref[...]
     n_lanes = ids_ref.shape[1]
     for lane in range(n_lanes):
-        lid = ids_ref[:, lane]
-        lval = val_ref[:, lane] != 0
-        tab, fresh = lane_step(tab, lid, lval, shift=shift)
-        fresh_ref[:, lane] = fresh.astype(jnp.int32)
+        lid = ids_ref[:, lane:lane + 1]
+        lval = val_ref[:, lane:lane + 1] != 0
+        tab, fresh = lane_step(tab, lid, lval, n_buckets=n_buckets)
+        fresh_ref[:, lane:lane + 1] = fresh.astype(jnp.int32)
     tab_out[...] = tab
 
 
 def visited_filter_pallas(table, ids, valid, *, interpret: bool = False):
-    """Pallas path: grid over row blocks, the [bs, W, S] table block stays
+    """Pallas path: grid over row blocks, the [bs, W*S] table block stays
     VMEM-resident across all lanes of the call (the XLA path re-streams it
     per scan step).  Same :func:`lane_step` arithmetic — bitwise the
     reference."""
     B, W, S = table.shape
     M = ids.shape[1]
-    shift = shift_for(S)
-    # block small enough that table + ids + masks sit comfortably in VMEM
-    bs = 1
-    while bs * 2 <= min(B, 8) and (2 * bs) * W * S * 4 <= (1 << 20):
-        bs *= 2
+    shift_for(S)  # validates the bucket count
+    # 8 rows per block (one sublane tile); a smaller batch is one block
+    bs = 8 if B >= 8 else B
     Bp = -(-B // bs) * bs
+    tab = table.reshape(B, W * S)
     if Bp != B:
-        pad = ((0, Bp - B),)
-        table = jnp.pad(table, pad + ((0, 0), (0, 0)),
-                        constant_values=int(VF_EMPTY))
-        ids = jnp.pad(ids, pad + ((0, 0),))
-        valid = jnp.pad(valid, pad + ((0, 0),))
+        pad = ((0, Bp - B), (0, 0))
+        tab = jnp.pad(tab, pad, constant_values=int(VF_EMPTY))
+        ids = jnp.pad(ids, pad)
+        valid = jnp.pad(valid, pad)
     table2, fresh = pl.pallas_call(
-        functools.partial(_vf_kernel, shift=shift),
+        functools.partial(_vf_kernel, n_buckets=S),
         grid=(Bp // bs,),
         in_specs=[pl.BlockSpec((bs, M), lambda i: (i, 0)),
                   pl.BlockSpec((bs, M), lambda i: (i, 0)),
-                  pl.BlockSpec((bs, W, S), lambda i: (i, 0, 0))],
-        out_specs=[pl.BlockSpec((bs, W, S), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((bs, W * S), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((bs, W * S), lambda i: (i, 0)),
                    pl.BlockSpec((bs, M), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((Bp, W, S), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((Bp, W * S), jnp.int32),
                    jax.ShapeDtypeStruct((Bp, M), jnp.int32)],
         interpret=interpret,
-    )(ids, valid.astype(jnp.int32), table)
-    return table2[:B], fresh[:B] != 0
+    )(ids, valid.astype(jnp.int32), tab)
+    return table2[:B].reshape(B, W, S), fresh[:B] != 0

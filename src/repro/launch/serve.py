@@ -107,6 +107,9 @@ def main() -> None:
     from repro.ann import Index
     from repro.configs import get_arch
     from repro.data.synthetic import make_clustered, recall_at_k
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     # validate router flags before any expensive build (did-you-mean
     # messages come from parse_router_spec, consistent with get_arch)

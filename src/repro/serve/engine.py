@@ -451,8 +451,10 @@ class ANNEngine:
                 st.total_s += dt
                 st.steady_queries += B
             st.per_regime[kind].record(B, dt, warmup=compiled_now)
-        # padded rows are discarded before any caller-visible merge
-        return np.asarray(ids[:B]), np.asarray(dists[:B])
+        # padded rows are discarded before any caller-visible merge; slice
+        # on host: a mesh plane's outputs carry explicit shardings, which
+        # refuse a plain device-side slice
+        return np.asarray(ids)[:B], np.asarray(dists)[:B]
 
     # -- streaming mutability (DESIGN.md §7) --------------------------------
 

@@ -267,9 +267,11 @@ class SingleDevicePlane(_SnapshotPlane):
     def __init__(self, X, cfg: ANNConfig, *, graph: PackedGraph | None = None,
                  quant: tuple | None = None, packed: bool = False):
         self.cfg = cfg
-        # reusable pinned-host H2D staging routes (see stage_query)
+        # reusable pinned-host H2D staging routes (see stage_query); the
+        # route kind the last one took: "pinned_host" or "device_put"
         self._stage_puts = {}
         self.stage_reuses = 0
+        self.stage_route = None
         # kernel backend resolved once per plane; part of the engine's AOT
         # cache key so an engine rebuilt with a different backend never
         # aliases entries
@@ -372,8 +374,10 @@ class SingleDevicePlane(_SnapshotPlane):
 
             def put(Qh):
                 return jax.device_put(jax.device_put(Qh, pin), dst)
+            self.stage_route = "pinned_host"
             return put
         except Exception:  # noqa: BLE001 — capability probe
+            self.stage_route = "device_put"
             return lambda Qh: jax.device_put(jnp.asarray(Qh), dev)
 
     def stage_query(self, Qh):

@@ -65,10 +65,7 @@ def init_pod(coordinator: str = "localhost:29500", *,
         return
     if num_processes > 1:
         import jax
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — unknown on some jax versions
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)

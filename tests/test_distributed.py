@@ -47,14 +47,13 @@ def test_compressed_psum_matches_exact():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim import compression as C
-from repro.utils.compat import shard_map
 mesh = jax.make_mesh((4,), ('data',))
 g = jnp.arange(64, dtype=jnp.float32).reshape(4, 16) / 7.0
 err = jnp.zeros((4, 16))
 def f(gs, es):
     out, new_e = C.compressed_psum({'g': gs[0]}, {'g': es[0]}, 'data')
     return out['g'], new_e['g']
-fn = jax.jit(shard_map(f, mesh=mesh, in_specs=(P('data'), P('data')),
+fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P('data'), P('data')),
              out_specs=(P(), P('data')), check_vma=False))
 out, new_err = fn(g[:, None], err[:, None])
 exact = jnp.sum(g, axis=0)

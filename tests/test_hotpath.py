@@ -467,8 +467,9 @@ def test_gather_fused_fits_int8_headroom():
     fp32 = L2._gather_tile_bytes(64, 1024, 960, self_q=False)
     int8 = L2._gather_tile_bytes(64, 1024, 960, self_q=False, itemsize=1)
     assert int8 < fp32
-    # candidate-row DMA bytes (the 2*C*d double buffer) shrink exactly 4x
-    assert 2 * 1024 * 960 * 4 - 2 * 1024 * 960 == fp32 - int8 + 1024 * 4
+    # candidate-row DMA bytes (the 2*C*row double buffer) shrink from
+    # d*4 to d bytes rounded up to whole 512-byte packed-word rows
+    assert 2 * 1024 * 960 * 4 - 2 * 1024 * 1024 == fp32 - int8 + 1024 * 4
 
 
 def test_pick_bs_itemsize_aware(rng):
@@ -502,3 +503,43 @@ def test_gather_dispatch_pinned():
     assert HP.gather_dispatch("off", interp=False, fits=True) is False
     with pytest.raises(ValueError, match="gather_fused"):
         HP.gather_dispatch("always", interp=False, fits=True)
+
+
+def test_pallas_paths_are_recorded(rng):
+    """Each Pallas primitive notes the path it took and whether it ran in
+    interpret mode (what chip_smoke.py reports and gates on)."""
+    N, d, S, C = 50, 8, 4, 6
+    X = jnp.asarray(rng.normal(size=(N, d)).astype(np.float32))
+    Q = jnp.asarray(rng.normal(size=(S, d)).astype(np.float32))
+    idx = jnp.asarray(rng.integers(0, N, size=(S, C)).astype(np.int32))
+    HP.PATHS.clear()
+    dist = HP.neighbor_distances(Q, X, idx, backend="pallas",
+                                 gather_fused="on", interpret=True)
+    HP.rank_merge(dist, idx, keep=2, backend="pallas", interpret=True)
+    HP.neighbor_distances(Q, X, idx, backend="pallas", gather_fused="off",
+                          interpret=True)
+    assert HP.PATHS.get(("neighbor_distances", "fused_gather", True))
+    assert HP.PATHS.get(("neighbor_distances", "gather_then_block", True))
+    assert HP.PATHS.get(("rank_merge", "bitonic", True))
+    HP.PATHS.clear()
+    HP.neighbor_distances(Q, X, idx, backend="xla")
+    assert not HP.PATHS  # the reference backend runs no kernel
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins where set; otherwise the cache sits
+    at a fixed .jax_cache/ in the checkout."""
+    from repro.utils import compile_cache as CC
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert CC.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = CC.use_compile_cache()
+        assert path == str(CC.CHECKOUT / ".jax_cache")
+        assert (CC.CHECKOUT / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -411,6 +411,8 @@ def test_h2d_staging_counter(corpus, base_kwargs):
     # per-(shape, dtype) cached, not rebuilt per call
     assert st.h2d_stage_reuses >= 2
     assert st.snapshot()["h2d_stage_reuses"] == st.h2d_stage_reuses
+    # the plane names the route the staging took
+    assert idx.plane.stage_route in ("pinned_host", "device_put")
 
 
 def test_gather_limit_rejected_on_packed_graph(base_kwargs):

@@ -66,6 +66,18 @@ def test_nn_descent_converges(ds, knn):
     assert hits / len(range(0, 4000, 97)) > 0.85
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_nn_descent_row_tiles_match_one_tile(backend):
+    """Rows are independent: evaluating candidates in row tiles (with a
+    padded last tile) gives the graph one tile over all rows gives."""
+    X = jnp.asarray(np.random.default_rng(3).normal(size=(1000, 16))
+                    .astype(np.float32))
+    one = nn_descent(X, 8, iters=3, backend=backend)
+    tiled = nn_descent(X, 8, iters=3, tile=300, backend=backend)
+    np.testing.assert_array_equal(np.asarray(one[0]), np.asarray(tiled[0]))
+    np.testing.assert_array_equal(np.asarray(one[1]), np.asarray(tiled[1]))
+
+
 def test_reverse_neighbors_correct():
     ids = jnp.asarray([[1, 2], [2, 3], [0, 3], [0, 1]], jnp.int32)
     rev = reverse_neighbors(ids, ids < 4, cap=4)
