@@ -1,0 +1,10 @@
+"""Padded rows per useful query in the engine (serve/engine.py) over the
+whole window: ``ServeStats.padded_queries`` over the queries answered.
+The bucket ladder's waste on open-loop dispatches; moves p99_ms."""
+
+
+def read(ctx):
+    w = ctx["window"]
+    if not w or not w["queries"]:
+        return None
+    return w["padded"] / w["queries"]
