@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core import hotpath as HP
 from repro.core.diversify import PackedGraph
+from repro.utils.trace import scoped
 
 INF = jnp.float32(3.4e38)
 
@@ -53,6 +54,7 @@ def _seg_merge(d3, i3, keep: int, backend: str):
                      "push_all_seeds", "unroll", "gather_limit",
                      "exact_visited", "backend", "gather_fused",
                      "rerank_mult", "visited"))
+@scoped("search_large")
 def _large_batch_search(X, graph: PackedGraph, Q, *, k: int = 10,
                        ef: int = 64, hops: int = 128, lambda_limit: int = 5,
                        metric: str = "l2", n_seeds: int = 32,
@@ -287,16 +289,18 @@ def _large_batch_search(X, graph: PackedGraph, Q, *, k: int = 10,
             V_ptr2 = V_ptr.at[rows, vs].add(1)
             V2 = jnp.where(now_done[:, None, None], V, V2)
             V_ptr2 = jnp.where(now_done[:, None], V_ptr, V_ptr2)
-            # membership tests: e ∉ V and e ∉ C (paper line 15)
-            in_V = jnp.any(V2[rows[:, None], _ext_hash(e_safe) % mv_seg]
-                           == e_safe[:, :, None], axis=2)
-            c_seg = _ext_hash(e_safe) % m_seg
-            c_rows_ids = C_ids2[rows[:, None], c_seg]           # [B, M, seg]
-            c_rows_d = C_d2[rows[:, None], c_seg]
-            in_C = jnp.any((c_rows_ids == e_safe[:, :, None])
-                           & (c_rows_d < INF), axis=2)
-            in_R = jnp.any((R_ids[:, None, :] == e_safe[:, :, None])
-                           & (R_d[:, None, :] < INF), axis=2)
+            # membership tests: e ∉ V and e ∉ C (paper line 15); the
+            # [B, M, seg] segment gathers of V and C are named in traces
+            with jax.named_scope("membership_scan"):
+                in_V = jnp.any(V2[rows[:, None], _ext_hash(e_safe) % mv_seg]
+                               == e_safe[:, :, None], axis=2)
+                c_seg = _ext_hash(e_safe) % m_seg
+                c_rows_ids = C_ids2[rows[:, None], c_seg]       # [B, M, seg]
+                c_rows_d = C_d2[rows[:, None], c_seg]
+                in_C = jnp.any((c_rows_ids == e_safe[:, :, None])
+                               & (c_rows_d < INF), axis=2)
+                in_R = jnp.any((R_ids[:, None, :] == e_safe[:, :, None])
+                               & (R_d[:, None, :] < INF), axis=2)
             new = ok & ~in_V & ~in_C & ~in_R & ~dup_here
 
         # ---- distances for new candidates: ONE fused gather+GEMM+mask
@@ -313,14 +317,15 @@ def _large_batch_search(X, graph: PackedGraph, Q, *, k: int = 10,
         R_d3, R_ids3 = HP.rank_merge(cat_d, cat_i, keep=ef, backend=backend)
 
         # ---- push into C: per-segment insert, evict most distant ------
-        seg_of_e = _ext_hash(e_safe) % m_seg
-        cand_mask = (ed < INF)[:, None, :] \
-            & (seg_of_e[:, None, :] == jnp.arange(m_seg)[None, :, None])
-        cand_d = jnp.where(cand_mask, ed[:, None, :], INF)  # [B, m, M]
-        cand_i = jnp.where(cand_mask, e[:, None, :], N)
-        C_d3, C_ids3 = _seg_merge(
-            jnp.concatenate([C_d2, cand_d], axis=2),
-            jnp.concatenate([C_ids2, cand_i], axis=2), seg, backend)
+        with jax.named_scope("c_queue_evict"):
+            seg_of_e = _ext_hash(e_safe) % m_seg
+            cand_mask = (ed < INF)[:, None, :] \
+                & (seg_of_e[:, None, :] == jnp.arange(m_seg)[None, :, None])
+            cand_d = jnp.where(cand_mask, ed[:, None, :], INF)  # [B, m, M]
+            cand_i = jnp.where(cand_mask, e[:, None, :], N)
+            C_d3, C_ids3 = _seg_merge(
+                jnp.concatenate([C_d2, cand_d], axis=2),
+                jnp.concatenate([C_ids2, cand_i], axis=2), seg, backend)
 
         R_d4 = jnp.where(now_done[:, None], R_d, R_d3)
         R_ids4 = jnp.where(now_done[:, None], R_ids, R_ids3)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from repro.core import hotpath as HP
 from repro.core.diversify import PackedGraph
+from repro.utils.trace import scoped
 
 INF = jnp.float32(3.4e38)
 
@@ -47,6 +48,7 @@ INF = jnp.float32(3.4e38)
                      "lambda_limit", "metric", "exact_merge", "width",
                      "unroll", "backend", "gather_fused", "t0_total",
                      "rerank_mult", "visited"))
+@scoped("search_small")
 def _small_batch_search(X, graph: PackedGraph, Q, *, k: int = 10,
                        t0: int = 32, hops: int = 6, hop_width: int = 32,
                        n_seeds: int = 32, lambda_limit: int = 10,

@@ -57,7 +57,7 @@ def distance_matrix_pallas(Q, X, *, metric: str = "l2", bq: int = 128,
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name="dense_distances",
     )(Qp, Xp)
     return out[:B, :N]
 
@@ -191,6 +191,7 @@ def block_distances_pallas(Q, V, mask, v_scales=None, *, metric: str = "l2",
     mp = jnp.pad(mask.astype(jnp.int8), ((0, Sp - S), (0, Cp - C)))
     if v_scales is None:
         kernel = functools.partial(_block_kernel, metric=metric)
+        name = "block_distances"
         in_specs = [
             pl.BlockSpec((bs, Kq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((bs, bc, d), lambda i, j: (i, j, 0)),
@@ -202,6 +203,7 @@ def block_distances_pallas(Q, V, mask, v_scales=None, *, metric: str = "l2",
                      ((0, Sp - S), (0, Cp - C)))
         kernel = functools.partial(_block_kernel_quant, metric=metric,
                                    pin=interpret)
+        name = "block_distances_int8"
         in_specs = [
             pl.BlockSpec((bs, Kq, d), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((bs, bc, d), lambda i, j: (i, j, 0)),
@@ -215,7 +217,7 @@ def block_distances_pallas(Q, V, mask, v_scales=None, *, metric: str = "l2",
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bs, Kq, bc), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((Sp, Kq, Cp), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(*args)
     return out[:S, :, :C]
 
@@ -480,11 +482,13 @@ def gather_block_distances_pallas(Q, X, idx, mask, scales=None, *,
     if self_q:
         kernel = functools.partial(_self_q_gather_kernel, metric=metric,
                                    bs=bs, C=C, d=d)
+        name = "gather_pair_distances"
         in_specs = ids_specs + [row_spec, x_spec]
         args = (ip, ip, mp, X)
     elif scales is not None:
         kernel = functools.partial(_gather_block_kernel_quant, metric=metric,
                                    bs=bs, C=C, d=d, pin=interpret)
+        name = "gather_distances_int8"
         in_specs = ids_specs + [q_spec, row_spec, row_spec, x_spec]
         Qp = jnp.pad(Q, ((0, Sp - S), (0, 0), (0, 0)))
         sp = jnp.pad(scales.astype(jnp.float32), ((0, Sp - S), (0, 0)))
@@ -492,6 +496,7 @@ def gather_block_distances_pallas(Q, X, idx, mask, scales=None, *,
     else:
         kernel = functools.partial(_gather_block_kernel, metric=metric,
                                    bs=bs, C=C, d=d)
+        name = "gather_distances"
         in_specs = ids_specs + [q_spec, row_spec, x_spec]
         Qp = jnp.pad(Q, ((0, Sp - S), (0, 0), (0, 0)))
         args = (ip, ip, Qp, mp, X)
@@ -501,6 +506,6 @@ def gather_block_distances_pallas(Q, X, idx, mask, scales=None, *,
         scratch_shapes=[pltpu.VMEM((2, bs, C, X.shape[1]), X.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
         out_shape=jax.ShapeDtypeStruct((Sp, Kq, C), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name=name,
     )(*args)
     return out[:S]

@@ -117,12 +117,12 @@ def rank_merge_pallas(dists, ids, mask=None, *, keep: int,
             functools.partial(_sort_kernel, width=Wp),
             grid=(Rp // br,), in_specs=[spec, spec],
             out_specs=[spec, spec], out_shape=out_shape,
-            interpret=interpret)(dp, ip)
+            interpret=interpret, name="rank_merge")(dp, ip)
     else:
         mp = jnp.pad(mask.astype(jnp.int8), ((0, Rp - R), (0, Wp - W)))
         od, oi = pl.pallas_call(
             functools.partial(_masked_sort_kernel, width=Wp),
             grid=(Rp // br,), in_specs=[spec, spec, spec],
             out_specs=[spec, spec], out_shape=out_shape,
-            interpret=interpret)(dp, ip, mp)
+            interpret=interpret, name="rank_merge_masked")(dp, ip, mp)
     return od[:R, :keep], oi[:R, :keep]

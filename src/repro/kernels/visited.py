@@ -136,6 +136,6 @@ def visited_filter_pallas(table, ids, valid, *, interpret: bool = False):
                    pl.BlockSpec((bs, M), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((Bp, W * S), jnp.int32),
                    jax.ShapeDtypeStruct((Bp, M), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="visited_filter",
     )(ids, valid.astype(jnp.int32), tab)
     return table2[:B].reshape(B, W, S), fresh[:B] != 0

@@ -279,7 +279,9 @@ def main() -> None:
               f"lost_futures={rt['lost_futures']} "
               f"retries={rt['retries']} ejects={rt['ejects']} "
               f"readmits={rt['readmits']} probes={rt['probes']} "
-              f"expired={agg['expired']}")
+              f"expired={agg['expired']} "
+              f"queue_wait_ms={agg['queue_wait_ms']:.2f} "
+              f"service_ms={agg['service_ms']:.2f}")
     else:
         s = index.stats
         print(f"[serve] {s.n_queries} queries / {s.n_batches} batches "

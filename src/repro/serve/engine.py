@@ -29,7 +29,7 @@ Serving additions on top of the paper:
   ladder / compile cache / donation / stats all thread through the plane,
   a mesh engine gets per-(regime, bucket, k) cached executables, padded
   donated batches, AOT persistence and percentile stats for free.
-* **Stats v2** — per-regime latency records (percentiles/histograms),
+* **Stats v2** — per-regime latency records (percentiles),
   compile and bucket-hit counters, and warmup (compile-triggering) batches
   excluded from steady-state QPS.
 * **Streaming mutability (DESIGN.md §7)** — :meth:`add` appends vectors to
@@ -69,6 +69,7 @@ from repro.ann.dispatch import regime_for
 from repro.configs.base import ANNConfig
 from repro.serve.plane import (MeshPlane, SingleDevicePlane, SMALL_WIDTH,
                                StaleGeneration)
+from repro.utils.trace import span
 
 # back-compat alias (pre-plane revisions defined the ranking width here)
 _SMALL_WIDTH = SMALL_WIDTH
@@ -103,12 +104,6 @@ class RegimeStats:
             return {f"p{q}": float("nan") for q in qs}
         arr = np.asarray(self.latencies_s)
         return {f"p{q}": float(np.percentile(arr, q)) for q in qs}
-
-    def histogram(self, bins: int = 16):
-        """(counts, edges_s) over steady-state batch latencies."""
-        if not self.latencies_s:
-            return np.zeros((bins,), np.int64), np.zeros((bins + 1,))
-        return np.histogram(np.asarray(self.latencies_s), bins=bins)
 
 
 @dataclasses.dataclass
@@ -340,10 +335,11 @@ class ANNEngine:
             hit = self._compiled.get(cache_key)
         if hit is not None:
             return hit, False
-        if streaming:
-            exe = self.plane.compile_stream(kind, bucket, k)
-        else:
-            exe = self.plane.compile(kind, bucket, k)
+        with span("engine.compile", regime=kind, bucket=bucket, k=k):
+            if streaming:
+                exe = self.plane.compile_stream(kind, bucket, k)
+            else:
+                exe = self.plane.compile(kind, bucket, k)
         with self._lock:
             # a racing thread may have compiled the same key; keep the first
             prior = self._compiled.get(cache_key)
@@ -370,31 +366,58 @@ class ANNEngine:
 
     def query(self, Q, *, k: int | None = None):
         """Answer a batch: (ids [B, k], dists [B, k]) numpy arrays."""
+        call = span("engine.query")
+        with call:
+            with span("engine.prepare"):
+                Q, host, kind, k, bucket = self._prepare(Q, k)
+            B = Q.shape[0]
+            call.set_metadata(regime=kind, bucket=bucket, queries=B)
+            ids, dists = self._execute(Q, host, kind, k, bucket)
+            with span("engine.fetch"):
+                # padded rows are discarded before any caller-visible
+                # merge; slice on host: a mesh plane's outputs carry
+                # explicit shardings, which refuse a plain device-side slice
+                return np.asarray(ids)[:B], np.asarray(dists)[:B]
+
+    def _prepare(self, Q, k):
+        """Checks and shapes one batch: (Q, host, regime, k, bucket).
+        ``host`` is the batch edge-padded to its bucket on the host, where
+        the plane stages host batches (else None, and ``Q`` a device
+        array)."""
         Q_in = Q
         Q = self._check_numeric(Q, "Q")
-        stage = getattr(self.plane, "stage_query", None)
         host = None
-        if stage is not None and not isinstance(Q_in, jax.Array):
+        if (getattr(self.plane, "stage_query", None) is not None
+                and not isinstance(Q_in, jax.Array)):
             # host-resident batch on a staging-capable plane: keep it on
             # host, pad there, and let the plane move it through its
             # reusable pinned-host bounce route (one H2D DMA per call)
-            host = np.ascontiguousarray(np.asarray(Q, np.float32))
-            q_shape = host.shape
+            Q = host = np.ascontiguousarray(np.asarray(Q, np.float32))
         else:
             Q = (jnp.asarray(Q, jnp.float32) if Q is not Q_in
                  else jnp.asarray(Q))
             if Q.dtype != jnp.float32:
                 Q = Q.astype(jnp.float32)
-            q_shape = tuple(Q.shape)
-        if len(q_shape) != 2 or q_shape[1] != self.X.shape[1]:
+        if Q.ndim != 2 or Q.shape[1] != self.X.shape[1]:
             raise ValueError(
-                f"Q must be [B, {self.X.shape[1]}], got {tuple(q_shape)}")
-        B = q_shape[0]
+                f"Q must be [B, {self.X.shape[1]}], got {tuple(Q.shape)}")
+        B = Q.shape[0]
         if B == 0:
             raise ValueError("empty query batch")
         kind = self.regime(B)
         k = self._validate_k(k, kind)
         bucket = self.bucket_for(B)
+        if host is not None and bucket > B:
+            # edge-pad on host (bitwise the jnp.pad of the device path:
+            # row replication)
+            host = np.pad(host, ((0, bucket - B), (0, 0)), mode="edge")
+        return Q, host, kind, k, bucket
+
+    def _execute(self, Q, host, kind: str, k: int, bucket: int):
+        """Run the (regime, bucket, k) executable on the padded batch until
+        its ids are ready, and count the call; returns device (ids, dists)
+        of ``bucket`` rows."""
+        B = Q.shape[0]
         # dispatch loop: a concurrent compaction/add can swap the plane's
         # generation between executable lookup and call — the stale binding
         # raises StaleGeneration and we re-dispatch against the new token
@@ -402,12 +425,9 @@ class ANNEngine:
         for _ in range(3):
             streaming = self.plane.stream_active
             if host is not None:
-                # edge-pad on host (bitwise the jnp.pad below: row
-                # replication), then one staged H2D transfer; the staged
-                # array is freshly ours, safe to donate
-                Qh = (host if bucket == B else
-                      np.pad(host, ((0, bucket - B), (0, 0)), mode="edge"))
-                Qpad = stage(Qh)
+                # one staged H2D transfer; the staged array is freshly
+                # ours, safe to donate
+                Qpad = self.plane.stage_query(host)
             elif bucket > B:
                 Qpad = jnp.pad(Q, ((0, bucket - B), (0, 0)), mode="edge")
             elif self._donate:
@@ -420,7 +440,9 @@ class ANNEngine:
                                                      streaming)
             t0 = time.perf_counter()
             try:
-                ids, dists = exe(Qpad)
+                with span("engine.execute"):
+                    ids, dists = exe(Qpad)
+                    ids.block_until_ready()
             except StaleGeneration:
                 continue
             break
@@ -428,7 +450,6 @@ class ANNEngine:
             raise RuntimeError(
                 "query kept racing generation swaps; mutation rate "
                 "outpaces dispatch")
-        ids.block_until_ready()
         dt = time.perf_counter() - t0
         with self._lock:
             st = self.stats
@@ -451,10 +472,7 @@ class ANNEngine:
                 st.total_s += dt
                 st.steady_queries += B
             st.per_regime[kind].record(B, dt, warmup=compiled_now)
-        # padded rows are discarded before any caller-visible merge; slice
-        # on host: a mesh plane's outputs carry explicit shardings, which
-        # refuse a plain device-side slice
-        return np.asarray(ids)[:B], np.asarray(dists)[:B]
+        return ids, dists
 
     # -- streaming mutability (DESIGN.md §7) --------------------------------
 

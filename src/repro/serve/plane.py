@@ -70,6 +70,7 @@ import jax.numpy as jnp
 from repro.configs.base import ANNConfig
 from repro.core import hotpath
 from repro.core.diversify import PackedGraph
+from repro.utils.trace import span
 
 
 class StaleGeneration(RuntimeError):
@@ -388,13 +389,15 @@ class SingleDevicePlane(_SnapshotPlane):
         (surfaced as ``ServeStats.h2d_stage_reuses``, the proof that
         steady-state traffic reuses the staging buffer instead of setting
         up a fresh transfer path per call)."""
-        key = (tuple(Qh.shape), str(Qh.dtype))
-        put = self._stage_puts.get(key)
-        if put is None:
-            put = self._stage_puts[key] = self._make_stage(Qh.shape, Qh.dtype)
-        else:
-            self.stage_reuses += 1
-        return put(Qh)
+        with span("plane.stage"):
+            key = (tuple(Qh.shape), str(Qh.dtype))
+            put = self._stage_puts.get(key)
+            if put is None:
+                put = self._stage_puts[key] = self._make_stage(Qh.shape,
+                                                               Qh.dtype)
+            else:
+                self.stage_reuses += 1
+            return put(Qh)
 
     # -- lowering -----------------------------------------------------------
 

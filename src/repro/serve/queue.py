@@ -26,6 +26,8 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro.utils.trace import span
+
 
 class DeadlineExceeded(TimeoutError):
     """The request's ``deadline_ms`` elapsed before it was dispatched.
@@ -41,6 +43,7 @@ class _Request:
     single: bool           # caller passed a bare vector -> return [k] rows
     future: Future
     deadline: float | None = None   # absolute time.monotonic() cutoff
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 @dataclasses.dataclass
@@ -56,6 +59,11 @@ class BatcherStats:
     n_dispatches: int = 0
     bypass: int = 0                 # dispatches that took the QoS bypass lane
     expired: int = 0                # requests failed with DeadlineExceeded
+    # summed over dispatched requests: submit to the start of the request's
+    # dispatch, and that start to its future being set (so a request's
+    # latency is wait + serve, seen from the batcher)
+    wait_s: float = 0.0
+    serve_s: float = 0.0
     # recent dispatch sizes only (bounded; the means use the counters)
     dispatch_sizes: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=8192))
@@ -63,14 +71,21 @@ class BatcherStats:
         default_factory=threading.Lock, repr=False, compare=False)
 
     def record_dispatch(self, n_requests: int, n_queries: int, *,
-                        bypass: bool = False) -> None:
+                        wait_s: float = 0.0, bypass: bool = False) -> int:
+        """Count one dispatch as it starts; returns its sequence number."""
         with self._lock:
             self.n_requests += n_requests
             self.n_queries += n_queries
             self.n_dispatches += 1
+            self.wait_s += wait_s
             if bypass:
                 self.bypass += 1
             self.dispatch_sizes.append(n_queries)
+            return self.n_dispatches
+
+    def record_served(self, serve_s: float) -> None:
+        with self._lock:
+            self.serve_s += serve_s
 
     def record_expired(self) -> None:
         with self._lock:
@@ -90,6 +105,8 @@ class BatcherStats:
                 "n_dispatches": self.n_dispatches,
                 "bypass": self.bypass,
                 "expired": self.expired,
+                "wait_s": self.wait_s,
+                "serve_s": self.serve_s,
                 "mean_coalesced":
                     self.n_queries / max(self.n_dispatches, 1),
                 "dispatch_sizes": tuple(self.dispatch_sizes),
@@ -316,13 +333,21 @@ class MicroBatcher:
         while first is not None and self._expired(first):
             self._expire(first)
             first = None
-        while first is None:
-            first = self._q.get()
-            if first is None:
-                return None
-            if self._expired(first):
-                self._expire(first)
-                first = None
+        if first is None:
+            with span("batcher.idle"):
+                while first is None:
+                    first = self._q.get()
+                    if first is None:
+                        return None
+                    if self._expired(first):
+                        self._expire(first)
+                        first = None
+        with span("batcher.coalesce"):
+            return self._coalesce(first)
+
+    def _coalesce(self, first: _Request) -> list:
+        """Gather same-k co-riders behind ``first`` until the group is full
+        or ``max_wait`` has passed since it was picked up."""
         group = [first]
         total = first.Q.shape[0]
         deadline = time.monotonic() + self.max_wait_s
@@ -348,22 +373,38 @@ class MicroBatcher:
         return group
 
     def _serve_group(self, group: list, *, bypass: bool = False) -> None:
-        """One coalesced dispatch: concat, query, slice results back out."""
-        Q = np.concatenate([r.Q for r in group], axis=0)
-        self.stats.record_dispatch(len(group), Q.shape[0], bypass=bypass)
-        try:
-            ids, dists = self.engine.query(Q, k=group[0].k)
-        except Exception as e:  # noqa: BLE001 — deliver, don't die
-            for r in group:
-                r.future.set_exception(e)
-            return
-        row = 0
-        for r in group:
-            b = r.Q.shape[0]
-            out = (ids[row], dists[row]) if r.single \
-                else (ids[row:row + b], dists[row:row + b])
-            r.future.set_result(out)
-            row += b
+        """One coalesced dispatch: concat, query, slice results back out.
+        Each request's wait (submit to now) and service (now to its future
+        being set, an error included) go into ``stats``."""
+        dispatch = span("batcher.dispatch")
+        with dispatch:
+            t0 = time.perf_counter()
+            n_queries = sum(r.Q.shape[0] for r in group)
+            seq = self.stats.record_dispatch(
+                len(group), n_queries, bypass=bypass,
+                wait_s=sum(t0 - r.t_submit for r in group))
+            dispatch.set_metadata(dispatch=seq, requests=len(group),
+                                  queries=n_queries)
+            Q = np.concatenate([r.Q for r in group], axis=0)
+            error = None
+            try:
+                ids, dists = self.engine.query(Q, k=group[0].k)
+            except Exception as e:  # noqa: BLE001 — deliver, don't die
+                error = e
+            served = 0.0
+            with span("batcher.resolve"):
+                row = 0
+                for r in group:
+                    if error is not None:
+                        r.future.set_exception(error)
+                    else:
+                        b = r.Q.shape[0]
+                        r.future.set_result(
+                            (ids[row], dists[row]) if r.single
+                            else (ids[row:row + b], dists[row:row + b]))
+                        row += b
+                    served += time.perf_counter() - t0
+            self.stats.record_served(served)
 
     def _loop(self) -> None:
         while True:

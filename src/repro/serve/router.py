@@ -33,8 +33,9 @@ readmitted after ``readmit_probes`` consecutive successful probes.
 :class:`RouterStats` aggregates the per-replica
 :class:`~repro.serve.engine.ServeStats` (compiles, regimes, latency
 percentiles) and :class:`~repro.serve.queue.BatcherStats` (expired
-deadlines) plus the router's own counters (dispatches, retries, ejects,
-readmits, lost futures) into one ``Router.snapshot()`` dict.
+deadlines, queue wait and service time per request) plus the router's own
+counters (dispatches, retries, ejects, readmits, lost futures) into one
+``Router.snapshot()`` dict.
 
 Wire-up is the facade: ``Index.serve(router=RouterConfig(...))``; the
 launcher exposes ``--router replicated:N|sharded:N``.  Endpoints are
@@ -761,7 +762,8 @@ class Router:
         replicas = {}
         agg = {"n_queries": 0, "n_batches": 0, "small_batches": 0,
                "large_batches": 0, "compiles": 0, "aot_primed": 0,
-               "expired": 0, "qps": 0.0}
+               "expired": 0, "qps": 0.0, "wait_s": 0.0, "serve_s": 0.0}
+        queued = 0
         windows = {"small": [], "large": []}
         for (rep, healthy, inflight, dispatches, failures, ejects,
              readmits, last_error) in states:
@@ -782,6 +784,13 @@ class Router:
                 agg[key] += e[key]
             agg["qps"] += e["qps"]
             agg["expired"] += q["expired"]
+            agg["wait_s"] += q["wait_s"]
+            agg["serve_s"] += q["serve_s"]
+            queued += q["n_requests"]
+        # per request a replica's batcher dispatched: submit to dispatch,
+        # dispatch to answer
+        agg["queue_wait_ms"] = agg["wait_s"] / max(queued, 1) * 1e3
+        agg["service_ms"] = agg["serve_s"] / max(queued, 1) * 1e3
         for regime, window in windows.items():
             arr = np.asarray(window) if window else np.asarray([np.nan])
             for p in (50, 90, 99):

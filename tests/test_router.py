@@ -182,6 +182,24 @@ def test_replicated_shared_cache_zero_compiles(ds, idx):
     assert agg["large_p50_ms"] > 0.0
 
 
+def test_snapshot_aggregates_queue_wait_and_service(ds, idx):
+    """The aggregate sums each replica batcher's wait and service time and
+    gives their means per dispatched request."""
+    with idx.serve(router="replicated:2", max_batch=64) as r:
+        futs = [r.submit(ds.Q[i]) for i in range(6)]
+        done, not_done = wait(futs, timeout=120)
+        assert not not_done
+    snap = r.snapshot()    # closed: every dispatch has counted its service
+    agg, queues = snap["aggregate"], [v["queue"] for v in
+                                      snap["replicas"].values()]
+    assert sum(q["n_requests"] for q in queues) == 6
+    assert agg["wait_s"] == pytest.approx(sum(q["wait_s"] for q in queues))
+    assert agg["serve_s"] == pytest.approx(sum(q["serve_s"] for q in queues))
+    assert agg["serve_s"] > 0.0 and agg["wait_s"] >= 0.0
+    assert agg["service_ms"] == pytest.approx(agg["serve_s"] / 6 * 1e3)
+    assert agg["queue_wait_ms"] == pytest.approx(agg["wait_s"] / 6 * 1e3)
+
+
 def test_serve_router_accepts_spec_string(ds, idx):
     with idx.serve(router="replicated:2", max_wait_ms=0.5) as r:
         assert r.cfg.mode == "replicated" and r.cfg.replicas == 2

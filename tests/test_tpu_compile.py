@@ -12,6 +12,7 @@ The topology is described inside a fixture, never at import: only one
 process may hold the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,9 +112,28 @@ for _w in (32, 64, 512, 2048):
         [((_rows, _w), F32), ((_rows, _w), I32), ((_rows, _w), BOOL)])
 
 
+# the name each case's kernel carries in the program, and so in a trace
+KERNEL_NAMES = {
+    "block_fp32": "block_distances", "block_int8": "block_distances_int8",
+    "block_self_query": "block_distances", "delta_scan": "block_distances",
+    "gather_fp32_small_batch": "gather_distances",
+    "gather_fp32_large_batch": "gather_distances",
+    "gather_int8": "gather_distances_int8",
+    "gather_self_query": "gather_pair_distances",
+    "gather_nn_descent": "gather_distances",
+    "visited_filter": "visited_filter",
+}
+for _name in CASES:
+    if _name.startswith("rank_merge"):
+        KERNEL_NAMES[_name] = ("rank_merge_masked" if _name.endswith("masked")
+                               else "rank_merge")
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(spec, name):
     fn, shapes = CASES[name]
     args = [spec(shape, dtype) for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, name
+    assert re.search(rf"%{KERNEL_NAMES[name]}\.\d+ = .*tpu_custom_call",
+                     text), name
